@@ -1,15 +1,11 @@
 """Scheduler-core micro-benchmarks: allocation-algorithm costs at production
 batch sizes (the scheduler must tick every I_opt ≈ 10-80 ms; its own
-decision latency has to be orders of magnitude below that), plus the
-sharded-plane collective probes (EP all-to-all and the merged cross-DP
-decode step at 2/4/8 forced host devices) that calibrate
-``CostModel.with_measured_sync``."""
+decision latency has to be orders of magnitude below that).  Host-side
+only: the sharded plane's collectives are exercised on real devices by
+``chip_smoke.py --chips 4``."""
 from __future__ import annotations
 
-import os
 import random
-import subprocess
-import sys
 import time
 from typing import List
 
@@ -23,93 +19,6 @@ def _time(fn, reps=20):
     for _ in range(reps):
         fn()
     return (time.perf_counter() - t0) / reps * 1e6   # µs
-
-
-# Run in a SUBPROCESS per device count: the forced host-platform device
-# count must be pinned before jax initializes, and this process (like the
-# rest of the bench suite) stays on the normal 1-device platform.
-_SHARDED_PROBE = r'''
-import time
-
-import jax
-import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import NamedSharding, PartitionSpec as P
-
-from repro.config.base import get_arch
-from repro.launch.mesh import make_engine_mesh
-from repro.models.model import init_params
-from repro.serving.real_engine import EngineSpec
-
-NDEV = %(ndev)d
-mesh = make_engine_mesh(NDEV)
-cfg = get_arch("granite-moe-1b-a400m", reduced=True)
-
-
-def _t(fn, reps):
-    fn()                                    # warm (compile)
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        fn()
-    return (time.perf_counter() - t0) / reps * 1e6
-
-
-# Raw EP all-to-all round trip, sized like one MoE layer's activation
-# exchange: top_k * d_model bf16 per token, 64 tokens per DP rank,
-# dispatch + combine.
-buf = jax.device_put(
-    jnp.zeros((NDEV * 64, cfg.moe.top_k * cfg.d_model), jnp.bfloat16),
-    NamedSharding(mesh, P("data", None)))
-
-
-def _xchg(x):
-    y = jax.lax.all_to_all(x, "data", 0, 0, tiled=True)      # dispatch
-    return jax.lax.all_to_all(y, "data", 0, 0, tiled=True)   # combine
-
-
-a2a = jax.jit(shard_map(_xchg, mesh=mesh, in_specs=P("data", None),
-                        out_specs=P("data", None)))
-print("ep_a2a %%.1f" %% _t(lambda: jax.block_until_ready(a2a(buf)), 20))
-
-# Full merged cross-DP decode step (one mesh program over the whole
-# instance-wide paged cache; _LockedJit blocks until ready for us).
-params = init_params(cfg, jax.random.PRNGKey(0))
-spec = EngineSpec(cfg, params, max_len=64, max_batch=2, block_size=8,
-                  mesh=mesh)
-cache = spec.merged_paged_cache()
-toks = jnp.zeros((cache["cur"].shape[0], 1), jnp.int32)
-print("decode_step %%.1f"
-      %% _t(lambda: spec.jit_paged_decode(spec.params, toks, cache), 10))
-'''
-
-
-def _sharded_rows(report) -> List[str]:
-    rows: List[str] = []
-    report("\n## Sharded-plane collectives (subprocess per device count)")
-    report(f"{'op':>34} {'us/call':>10}")
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    for ndev in (2, 4, 8):
-        env = {**os.environ, "PYTHONPATH": "src",
-               "XLA_FLAGS": (f"--xla_force_host_platform_device_count={ndev} "
-                             + os.environ.get("XLA_FLAGS", ""))}
-        out = subprocess.run(
-            [sys.executable, "-c", _SHARDED_PROBE % {"ndev": ndev}],
-            capture_output=True, text=True, timeout=600, env=env, cwd=root)
-        if out.returncode != 0:
-            report(f"  {ndev}-device probe FAILED: "
-                   + out.stderr.strip()[-400:])
-            rows.append(f"micro/ep_a2a_{ndev}dev,NaN,FAILED")
-            rows.append(f"micro/sharded_decode_step_{ndev}dev,NaN,FAILED")
-            continue
-        vals = dict(line.split() for line in out.stdout.splitlines()
-                    if line.strip())
-        for key, name in (("ep_a2a", f"ep_a2a_{ndev}dev"),
-                          ("decode_step",
-                           f"sharded_decode_step_{ndev}dev")):
-            us = float(vals[key])
-            report(f"{name:>34} {us:>10.1f}")
-            rows.append(f"micro/{name},{us:.1f},")
-    return rows
 
 
 def main(report) -> List[str]:
@@ -191,5 +100,4 @@ def main(report) -> List[str]:
         report(f"{f'{name} churn (8-blk reqs, 64K pool)':>34} {us:>10.1f}")
         rows.append(f"micro/{name}_churn_64k,{us:.1f},")
 
-    rows.extend(_sharded_rows(report))
     return rows

@@ -7,7 +7,8 @@ Prints human-readable tables plus ``name,us_per_call,derived`` CSV lines
 ``BENCH_e2e.json`` (TTFT p50/p99 + throughput per scheduler per scenario
 from the e2e_pd bench) so the perf trajectory is machine-trackable across
 PRs; ``--quick`` asks benches that support it for a reduced sweep (the CI
-smoke path).
+smoke path).  A bench that raises is reported with its traceback, the
+remaining benches still run, and the run exits 1.
 """
 from __future__ import annotations
 
@@ -17,6 +18,7 @@ import json
 import os
 import sys
 import time
+import traceback
 from typing import List
 
 BENCHES = [
@@ -36,7 +38,8 @@ JSON_PATH = "BENCH_e2e.json"
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--only", default=None)
+    ap.add_argument("--only", default=None,
+                    choices=[name for name, _ in BENCHES])
     ap.add_argument("--quick", action="store_true",
                     help="reduced sweeps for benches that support it")
     ap.add_argument("--json", action="store_true",
@@ -45,6 +48,7 @@ def main() -> None:
 
     csv: List[str] = ["name,us_per_call,derived"]
     payload = None
+    failed: List[str] = []
     for mod_name, desc in BENCHES:
         if args.only and args.only != mod_name:
             continue
@@ -59,9 +63,11 @@ def main() -> None:
             csv.extend(rows or [])
             if getattr(mod, "JSON_PAYLOAD", None) is not None:
                 payload = mod.JSON_PAYLOAD
-        except Exception as e:
-            print(f"BENCH FAILED: {e!r}")
+        except Exception:
+            traceback.print_exc()
+            print(f"BENCH FAILED: {mod_name}")
             csv.append(f"{mod_name},NaN,FAILED")
+            failed.append(mod_name)
         print(f"[{mod_name} took {time.time()-t0:.1f}s]")
     if args.json:
         if payload is None:
@@ -84,6 +90,9 @@ def main() -> None:
     print(f"\n{'='*72}\n== CSV ==\n{'='*72}")
     for line in csv:
         print(line)
+    if failed:
+        print(f"failed benches: {', '.join(failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

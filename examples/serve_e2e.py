@@ -43,9 +43,10 @@ equal-or-higher throughput, and records both sides in the payload's
 
 `--sharded-bench` runs the sharded DP+EP A/B instead: the deployment is
 MESH-NATIVE (4 decode DP units merged into one cache sharded over a
-4-device forced-host mesh, every step a cross-DP program with the
-explicit EP all-to-all live), served under immediate dispatch vs SBS
-staggered batch formation.  Requires sbs-la to post a strictly lower
+4-device mesh, every step a cross-DP program with the explicit EP
+all-to-all live; on a CPU host, give the process 4 devices with
+XLA_FLAGS=--xla_force_host_platform_device_count=4), served under
+immediate dispatch vs SBS staggered batch formation.  Requires sbs-la to post a strictly lower
 TTFT p99 at equal-or-higher throughput, records per-step sync stall and
 the measured per-step sync cost that calibrates `CostModel.t_sync`, and
 writes the payload's `real_plane_sharded` section.  Use the granite MoE
@@ -57,15 +58,6 @@ import json
 import os
 import random
 import sys
-
-# --sharded-bench serves on a 4-device forced-host mesh; the device
-# count must be pinned BEFORE the first jax import (the same bootstrap
-# launch/dryrun.py uses), so peek at argv here
-if ("--sharded-bench" in sys.argv
-        and "xla_force_host_platform_device_count"
-        not in os.environ.get("XLA_FLAGS", "")):
-    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4 "
-                               + os.environ.get("XLA_FLAGS", ""))
 
 import jax
 
@@ -502,7 +494,7 @@ def _measure_step_sync(spec_sh, spec_lo, reps=20):
 def run_sharded_bench(cfg, params, args):
     """Sharded DP+EP A/B on the real plane: the SAME trace served by a
     mesh-native deployment — 4 decode DP units merged into ONE cache
-    sharded over a 4-device (forced host) mesh, every engine step a
+    sharded over a 4-device mesh, every engine step a
     cross-DP program with the explicit EP all-to-all active — under
     immediate dispatch vs SBS staggered batch formation.  Returns
     (ok, report-section).
@@ -531,9 +523,9 @@ def run_sharded_bench(cfg, params, args):
 
     n_dp = 4
     if len(jax.devices()) < n_dp:
-        print(f"sharded bench needs {n_dp} devices (forced host), have "
-              f"{len(jax.devices())} — run via --sharded-bench in a fresh "
-              f"process so the XLA_FLAGS bootstrap applies")
+        print(f"sharded bench needs {n_dp} devices, have "
+              f"{len(jax.devices())} (on a CPU host: XLA_FLAGS="
+              f"--xla_force_host_platform_device_count={n_dp})")
         return False, {}
     bs = args.block_size or 16
     mesh = make_engine_mesh(n_dp)
@@ -709,7 +701,7 @@ def main():
                          "ablation) instead of the scheduler sweep")
     ap.add_argument("--sharded-bench", action="store_true",
                     help="run the sharded DP+EP A/B (merged decode cache "
-                         "on a 4-device forced-host mesh, EP all-to-all "
+                         "on a 4-device mesh, EP all-to-all "
                          "live; immediate vs sbs-la) instead of the "
                          "scheduler sweep")
     args = ap.parse_args()
@@ -718,6 +710,8 @@ def main():
                  "with --block-size 0 the concurrency gate would silently "
                  "not run")
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     cfg = get_arch(args.arch, reduced=True)
     params = init_params(cfg, jax.random.PRNGKey(args.seed))
 

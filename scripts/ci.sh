@@ -81,7 +81,8 @@ if [[ "${1:-}" == "--real-smoke" ]]; then
                   "unfinished requests, or >600s)" >&2
              exit 1; }
     echo "== real-plane sharded DP+EP A/B (sbs-la vs immediate, 600s budget) =="
-    PYTHONPATH=src timeout 600 python examples/serve_e2e.py \
+    PYTHONPATH=src XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+        timeout 600 python examples/serve_e2e.py \
         --arch granite-moe-1b-a400m --timeout 150 \
         --sharded-bench --bench-json BENCH_e2e.json \
         || { echo "sharded smoke FAILED (EP all-to-all absent from step" \
@@ -111,21 +112,16 @@ else
 fi
 
 echo "== benchmark smoke (microbench) =="
-out=$(PYTHONPATH=src:. python benchmarks/run.py --only microbench)
-echo "$out"
-if grep -q "BENCH FAILED" <<<"$out"; then
-    echo "benchmark smoke FAILED" >&2
-    exit 1
-fi
+PYTHONPATH=src:. python benchmarks/run.py --only microbench \
+    || { echo "benchmark smoke FAILED" >&2; exit 1; }
 
 echo "== benchmark smoke (e2e_pd --quick --json -> BENCH_e2e.json) =="
 baseline=""
 if git show HEAD:BENCH_e2e.json >/tmp/bench_baseline.json 2>/dev/null; then
     baseline=/tmp/bench_baseline.json
 fi
-out=$(PYTHONPATH=src:. python benchmarks/run.py --only e2e_pd --quick --json)
-echo "$out"
-if grep -q "BENCH FAILED" <<<"$out" || [[ ! -s BENCH_e2e.json ]]; then
+if ! PYTHONPATH=src:. python benchmarks/run.py --only e2e_pd --quick --json \
+        || [[ ! -s BENCH_e2e.json ]]; then
     echo "e2e_pd smoke FAILED (no BENCH_e2e.json)" >&2
     exit 1
 fi

@@ -27,7 +27,12 @@ def make(reduced: bool = False) -> ModelConfig:
         d_ff=512, vocab_size=49155,
         attention=AttentionKind.GQA,
         layer_pattern=(LayerKind.MOE,),
-        moe=MoEConfig(num_experts=32, top_k=8, d_expert=512),
+        # capacity E/k = 4: every token keeps all its top-k experts, as
+        # in the published model, which routes without a capacity limit.
+        # A binding capacity would make a request's tokens depend on the
+        # other rows of its batch.
+        moe=MoEConfig(num_experts=32, top_k=8, d_expert=512,
+                      capacity_factor=4.0),
         tie_embeddings=True, max_seq_len=32768,
         source="hf:ibm-granite/granite-3.0-1b-a400m-base",
     )

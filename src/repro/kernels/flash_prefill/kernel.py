@@ -41,46 +41,41 @@ def _flash_kernel(
     q = q_ref[0, 0]                    # (G·bq, hd)  — G query heads folded
     k = k_ref[0]                       # (bk, hd)
     v = v_ref[0]
-    qpos = qpos_ref[0]                 # (bq,)
-    kvpos = kvpos_ref[0]               # (bk,)
-    qseg = qseg_ref[0]
-    kvseg = kvseg_ref[0]
+    qpos = qpos_ref[0, 0]              # (G·bq, 1) — already folded like q
+    qseg = qseg_ref[0, 0]
+    kvpos = kvpos_ref[0, 0]            # (1, bk)
+    kvseg = kvseg_ref[0, 0]
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale     # (G·bq, bk)
 
-    bq = qpos.shape[0]
-    G = q.shape[0] // bq
-    qpos_f = jnp.tile(qpos, (G,))
-    qseg_f = jnp.tile(qseg, (G,))
-    mask = (kvpos[None, :] >= 0) & (kvseg[None, :] == qseg_f[:, None])
-    mask &= qseg_f[:, None] >= 0
+    mask = (kvpos >= 0) & (kvseg == qseg) & (qseg >= 0)
     if causal:
-        mask &= qpos_f[:, None] >= kvpos[None, :]
+        mask &= qpos >= kvpos
     if window > 0:
-        mask &= (qpos_f[:, None] - kvpos[None, :]) < window
+        mask &= (qpos - kvpos) < window
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_scr[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     p = jnp.where(mask, p, 0.0)
     alpha = jnp.exp(m_prev - m_new)
-    l_new = l_scr[:, 0] * alpha + p.sum(axis=-1)
-    acc = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
+    l_new = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
+    acc = acc_scr[...] * alpha + jax.lax.dot_general(
         p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
-    m_scr[:, 0] = m_new
-    l_scr[:, 0] = l_new
+    m_scr[...] = m_new
+    l_scr[...] = l_new
     acc_scr[...] = acc
 
     @pl.when(ikv == kv_blocks - 1)
     def _finalize():
-        l = l_scr[:, 0]
+        l = l_scr[...]
         safe = jnp.maximum(l, 1e-30)
-        out = jnp.where(l[:, None] > 0, acc_scr[...] / safe[:, None], 0.0)
+        out = jnp.where(l > 0, acc_scr[...] / safe, 0.0)
         o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
@@ -117,10 +112,17 @@ def flash_prefill_pallas(
     kr = k.transpose(0, 2, 1, 3).reshape(B * K, Skv, hd)
     vr = v.transpose(0, 2, 1, 3).reshape(B * K, Skv, hd)
 
-    qpos_r = jnp.repeat(q_pos[:, None], K, 1).reshape(B * K, Sq)
-    kvpos_r = jnp.repeat(kv_pos[:, None], K, 1).reshape(B * K, Skv)
-    qseg_r = jnp.repeat(q_seg[:, None], K, 1).reshape(B * K, Sq)
-    kvseg_r = jnp.repeat(kv_seg[:, None], K, 1).reshape(B * K, Skv)
+    # positions/segments are shared by the K heads of a row (index maps
+    # take b // K). q-side ones are folded like the q tile into a
+    # (G·bq, 1) column; kv-side ones are (1, bk) rows. Both keep a unit
+    # axis so each block's last two dims equal the array's.
+    def q_col(a):
+        a = a.reshape(B, nq, 1, block_q)
+        return jnp.broadcast_to(a, (B, nq, G, block_q)).reshape(
+            B, nq, G * block_q, 1)
+
+    def kv_row(a):
+        return a.reshape(B, nkv, 1, block_kv)
 
     grid = (B * K, nq, nkv)
     kernel = functools.partial(
@@ -134,10 +136,14 @@ def flash_prefill_pallas(
             pl.BlockSpec((1, 1, G * block_q, hd), lambda b, iq, ik: (b, iq, 0, 0)),
             pl.BlockSpec((1, block_kv, hd), lambda b, iq, ik: (b, ik, 0)),
             pl.BlockSpec((1, block_kv, hd), lambda b, iq, ik: (b, ik, 0)),
-            pl.BlockSpec((1, block_q), lambda b, iq, ik: (b, iq)),
-            pl.BlockSpec((1, block_kv), lambda b, iq, ik: (b, ik)),
-            pl.BlockSpec((1, block_q), lambda b, iq, ik: (b, iq)),
-            pl.BlockSpec((1, block_kv), lambda b, iq, ik: (b, ik)),
+            pl.BlockSpec((1, 1, G * block_q, 1),
+                         lambda b, iq, ik: (b // K, iq, 0, 0)),
+            pl.BlockSpec((1, 1, 1, block_kv),
+                         lambda b, iq, ik: (b // K, ik, 0, 0)),
+            pl.BlockSpec((1, 1, G * block_q, 1),
+                         lambda b, iq, ik: (b // K, iq, 0, 0)),
+            pl.BlockSpec((1, 1, 1, block_kv),
+                         lambda b, iq, ik: (b // K, ik, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G * block_q, hd),
                                lambda b, iq, ik: (b, iq, 0, 0)),
@@ -150,7 +156,8 @@ def flash_prefill_pallas(
             pltpu.VMEM((G * block_q, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(qr, kr, vr, qpos_r, kvpos_r, qseg_r, kvseg_r)
+    )(qr, kr, vr, q_col(q_pos), kv_row(kv_pos), q_col(q_seg),
+      kv_row(kv_seg))
 
     # un-fold: (B·K, nq, G·bq, hd) -> (B, Sq, H, hd)
     out = out.reshape(B, K, nq, G, block_q, hd).transpose(0, 2, 4, 1, 3, 5)

@@ -1,6 +1,6 @@
 """Public jit'd wrapper for the flash_prefill kernel.
 
-On CPU (this container) the kernel body executes in interpret mode; on TPU
+On the CPU backend the kernel body executes in interpret mode; on TPU
 it lowers through Mosaic with the BlockSpec VMEM tiling in kernel.py.
 """
 from __future__ import annotations

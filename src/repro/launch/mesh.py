@@ -1,12 +1,20 @@
 """Production mesh construction.
 
 Defined as FUNCTIONS so importing this module never touches jax device
-state. The dry-run sets XLA_FLAGS=--xla_force_host_platform_device_count=512
-before any jax import; everything else sees the real (1-device) platform.
+state. Every mesh uses `Auto` axes: the model code places values with
+`with_sharding_constraint`, which refuses the `Explicit` axes
+`jax.make_mesh` now gives by default.
 """
 from __future__ import annotations
 
 import numpy as np
+
+
+def _auto_mesh(shape, axes, devices):
+    import jax
+    from jax.sharding import AxisType
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -20,27 +28,22 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"need {need} devices for {shape}, have {len(devices)} — run via "
             "launch/dryrun.py (it forces 512 host devices)")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return _auto_mesh(shape, axes, devices[:need])
 
 
 def make_engine_mesh(data: int, model: int = 1):
     """Mesh for the SHARDED real serving plane: one data-axis rank per
     decode DP unit (the merged paged cache's slot/pool dims shard over
-    "data"), `model` ranks of tensor parallelism inside each DP.  CI
-    drives this with forced host devices
-    (XLA_FLAGS=--xla_force_host_platform_device_count=N, set BEFORE the
-    first jax import); production uses the real accelerator topology."""
+    "data"), `model` ranks of tensor parallelism inside each DP, over
+    the first data×model devices the process sees."""
     import jax
     need = data * model
     devices = jax.devices()
     if len(devices) < need:
         raise RuntimeError(
             f"sharded plane needs {need} devices for a ({data},{model}) "
-            f"data×model mesh, have {len(devices)} — force host devices "
-            f"with XLA_FLAGS=--xla_force_host_platform_device_count={need} "
-            f"before the first jax import")
-    return jax.make_mesh((data, model), ("data", "model"),
-                         devices=devices[:need])
+            f"data×model mesh, have {len(devices)}")
+    return _auto_mesh((data, model), ("data", "model"), devices[:need])
 
 
 def make_test_mesh(data: int = 2, model: int = 2, pod: int = 0):
@@ -51,4 +54,4 @@ def make_test_mesh(data: int = 2, model: int = 2, pod: int = 0):
     else:
         shape, axes = (data, model), ("data", "model")
     need = int(np.prod(shape))
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:need])
+    return _auto_mesh(shape, axes, jax.devices()[:need])

@@ -20,7 +20,6 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.config.base import MoEConfig
@@ -126,12 +125,12 @@ def moe_block_ep(x: jnp.ndarray, params: Dict, mc: MoEConfig, mesh,
     tok_spec = P(token_axes if token_axes else None, None)
     w_spec = P(ep_axes if len(ep_axes) > 1 else ep_axes[0], None, None)
     bias = params.get("router_bias")
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tok_spec, P(None, None), w_spec, w_spec, w_spec,
                   P(None) if bias is not None else None),
         out_specs=(tok_spec, P()),
-        check_rep=False)
+        check_vma=False)
     out, laux = fn(x2d, params["router"], params["w_gate"], params["w_up"],
                    params["w_down"], bias)
 

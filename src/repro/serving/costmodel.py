@@ -56,9 +56,8 @@ class CostModel:
         """Replace the hardcoded per-pass sync constant with a MEASURED
         per-step collective time (sharded-step wall time minus the
         equivalent single-device step — see `examples/serve_e2e.py
-        --sharded-bench` and the `micro/ep_a2a_*` probes in
-        `benchmarks/microbench.py`), so simulator sweeps price the DP
-        barrier at what the mesh actually charges."""
+        --sharded-bench`), so simulator sweeps price the DP barrier at
+        what the mesh actually charges."""
         return dataclasses.replace(self, t_sync=max(float(t_sync), 0.0))
 
     def _kv_bytes_per_token(self) -> int:

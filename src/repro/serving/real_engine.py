@@ -60,6 +60,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 import queue
 import threading
 import time
@@ -191,6 +192,7 @@ class EngineSpec:
         self.n_dp = 1
         self.axis_map = None
         self._mesh_lock = threading.RLock()
+        self._cache_builders: Dict = {}   # (slots, blocks) -> jitted init
         if self.mesh is not None:
             self._init_sharded()
 
@@ -298,14 +300,23 @@ class EngineSpec:
             jax.block_until_ready(out)
         return out
 
-    def shard_cache(self, cache: Dict) -> Dict:
-        """Commit a freshly built paged cache to its mesh layout (no-op
-        for unsharded specs)."""
+    def new_paged_cache(self, slots: int, num_blocks: int) -> Dict:
+        """A fresh paged cache; on a mesh it is built by one jitted
+        program straight into its `paged_cache_pspecs` layout, so no
+        device ever holds the whole of it.  That program is jitted once
+        per cache shape and reused."""
+        make = functools.partial(init_paged_cache, self.cfg, slots,
+                                 num_blocks, self.max_len, self.block_size)
         if self.mesh is None:
-            return cache
-        from repro.distributed.sharding import named, paged_cache_pspecs
-        return jax.device_put(cache, named(self.mesh, paged_cache_pspecs(
-            self.cfg, self.mesh, self.parallel, cache)))
+            return make()
+        build = self._cache_builders.get((slots, num_blocks))
+        if build is None:
+            from repro.distributed.sharding import named, paged_cache_pspecs
+            layout = named(self.mesh, paged_cache_pspecs(
+                self.cfg, self.mesh, self.parallel, jax.eval_shape(make)))
+            build = jax.jit(make, out_shardings=layout)
+            self._cache_builders[(slots, num_blocks)] = build
+        return build()
 
     def merged_paged_cache(self) -> Dict:
         """ONE instance-wide decode cache holding every DP unit's rows
@@ -314,9 +325,8 @@ class EngineSpec:
         `BlockPool(base=...)` allocators — so both pool dims shard over
         the mesh's data axis and DP d's rows live on mesh rank d."""
         n = self.n_dp
-        return self.shard_cache(init_paged_cache(
-            self.cfg, n * self.paged_slots, n * self.paged_pool_blocks,
-            self.max_len, self.block_size))
+        return self.new_paged_cache(n * self.paged_slots,
+                                    n * self.paged_pool_blocks)
 
     @property
     def paged(self) -> bool:
@@ -369,14 +379,11 @@ class EngineSpec:
         return init_cache(self.cfg, self.max_batch, self.max_len)
 
     def paged_cache(self) -> Dict:
-        return self.shard_cache(init_paged_cache(
-            self.cfg, self.paged_slots, self.paged_pool_blocks,
-            self.max_len, self.block_size))
+        return self.new_paged_cache(self.paged_slots, self.paged_pool_blocks)
 
     def prefill_paged_cache(self) -> Dict:
-        return self.shard_cache(init_paged_cache(
-            self.cfg, self.paged_prefill_slots, self.paged_prefill_blocks,
-            self.max_len, self.block_size))
+        return self.new_paged_cache(self.paged_prefill_slots,
+                                    self.paged_prefill_blocks)
 
     def target_len(self, req: Request) -> int:
         if self.max_new:

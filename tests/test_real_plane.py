@@ -613,3 +613,20 @@ def test_cross_plane_equivalence(tiny_dense, plane):
     decoded = sum(e.tokens_generated for e in engines)
     first_from_prefill = 1 if kind == "real" else 0
     assert decoded == sum(r.output_len - first_from_prefill for r in reqs)
+
+
+@pytest.mark.parametrize("scheduler", ["sbs-la", "immediate"])
+def test_serve_real_matches_greedy_reference(scheduler):
+    """The entry point `chip_smoke.py` drives (`launch.serve.serve_real`),
+    at the reduced granite preset: every request finishes with its full
+    token count, and its tokens equal a single-request greedy decode of
+    the same params."""
+    from repro.launch.serve import greedy_mismatches, serve_real
+    run = serve_real("granite-moe-1b-a400m", reduced=True,
+                     schedulers=(scheduler,), n_requests=4, output_len=4,
+                     timeout=120)
+    gens = run.generations[scheduler]
+    assert [g.rid for g in gens] == [0, 1, 2, 3]
+    assert run.spec.paged and run.spec.block_size == 16
+    assert greedy_mismatches(run.cfg, run.params, run.requests[scheduler],
+                             gens) == []

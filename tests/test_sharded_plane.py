@@ -103,6 +103,33 @@ print("MIXED-EXACT-OK")
 """)
 
 
+def test_serve_real_sharded_matches_one_device():
+    """`chip_smoke.py --chips 4` at the reduced preset: `serve_real` on a
+    4-device mesh and the same deployment (decode DP = 4) on one device
+    give the same tokens, and each sharded cache shape is built by one
+    jitted program however many caches of it the deployment makes."""
+    out = _sub("""
+from repro.launch.mesh import make_engine_mesh
+from repro.launch.serve import serve_real
+
+kw = dict(reduced=True, n_requests=4, output_len=4, timeout=120)
+sh = serve_real("granite-moe-1b-a400m", mesh=make_engine_mesh(4), **kw)
+one = serve_real("granite-moe-1b-a400m", params=sh.params,
+                 decode_dp=sh.spec.n_dp, **kw)
+assert sh.spec.n_dp == 4 and one.spec.mesh is None
+for run in (sh, one):
+    assert run.servers["sbs-la"].scfg.decode_dp_per_instance == 4
+ts = {g.rid: g.tokens for g in sh.generations["sbs-la"]}
+t1 = {g.rid: g.tokens for g in one.generations["sbs-la"]}
+assert set(ts) == {0, 1, 2, 3} and ts == t1, (ts, t1)
+before = dict(sh.spec._cache_builders)
+sh.spec.merged_paged_cache()
+assert sh.spec._cache_builders == before, "cache builder re-jitted"
+print("SERVE-REAL-SHARDED-OK", len(before))
+""")
+    assert "SERVE-REAL-SHARDED-OK" in out
+
+
 def test_sharded_step_has_ep_all_to_all():
     """The compiled merged decode step is a genuine mesh program: the
     explicit EP all-to-all appears in its HLO, and the output cache
