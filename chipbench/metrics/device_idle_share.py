@@ -1,0 +1,9 @@
+"""Device layer: share of the traced window in which no operation ran
+on the chips (1 - busy / window, busy averaged over the chips)."""
+
+
+def read(run):
+    tr = run.trace
+    if not tr or not tr["window_s"]:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
