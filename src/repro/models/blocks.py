@@ -205,8 +205,9 @@ def attn_decode_paged(p, x, cfg: ModelConfig, k_pool, v_pool, kv_pos_pool,
     k_pool = k_pool.at[phys, off].set(k[:, 0].astype(k_pool.dtype))
     v_pool = v_pool.at[phys, off].set(v[:, 0].astype(v_pool.dtype))
     kv_pos_pool = kv_pos_pool.at[phys, off].set(pos)
-    o = A.decode_attention_paged(q, k_pool, v_pool, kv_pos_pool, block_tab,
-                                 pos, window)
+    with jax.named_scope("attention"):
+        o = A.decode_attention_paged(q, k_pool, v_pool, kv_pos_pool,
+                                     block_tab, pos, window)
     out = jnp.einsum("bshe,hed->bsd", o, p["w_o"])
     return out, (k_pool, v_pool, kv_pos_pool)
 
@@ -228,15 +229,16 @@ def mla_decode_paged(p, x, cfg: ModelConfig, ckv_pool, kr_pool, kv_pos_pool,
     ckv_pool = ckv_pool.at[phys, off].set(ckv.astype(ckv_pool.dtype))
     kr_pool = kr_pool.at[phys, off].set(kr.astype(kr_pool.dtype))
     kv_pos_pool = kv_pos_pool.at[phys, off].set(pos)
-    ckv_g = A.gather_paged(ckv_pool, block_tab)
-    kr_g = A.gather_paged(kr_pool, block_tab)
-    kv_pos_g = A.gather_paged_pos(kv_pos_pool, block_tab)
     q_lat = jnp.einsum("bhd,rhd->bhr", q_nope, p["w_uk"])
-    pattn, _ = A.mla_scores_decode(
-        (q_lat * scale).astype(ckv_g.dtype),
-        (q_rope * scale).astype(kr_g.dtype),
-        ckv_g, kr_g, kv_pos_g, pos)
-    ctx = jnp.einsum("bhs,bsr->bhr", pattn.astype(ckv_g.dtype), ckv_g)
+    with jax.named_scope("attention"):
+        ckv_g = A.gather_paged(ckv_pool, block_tab)
+        kr_g = A.gather_paged(kr_pool, block_tab)
+        kv_pos_g = A.gather_paged_pos(kv_pos_pool, block_tab)
+        pattn, _ = A.mla_scores_decode(
+            (q_lat * scale).astype(ckv_g.dtype),
+            (q_rope * scale).astype(kr_g.dtype),
+            ckv_g, kr_g, kv_pos_g, pos)
+        ctx = jnp.einsum("bhs,bsr->bhr", pattn.astype(ckv_g.dtype), ckv_g)
     o = jnp.einsum("bhr,rhe->bhe", ctx, p["w_uv"])
     out = jnp.einsum("bhe,hed->bd", o, p["w_o"])[:, None]
     return out, (ckv_pool, kr_pool, kv_pos_pool)

@@ -84,6 +84,7 @@ def aux_loss(probs: jnp.ndarray, top_e: jnp.ndarray, num_experts: int) -> jnp.nd
     return num_experts * jnp.sum(f * p)
 
 
+@jax.named_scope("moe")
 def moe_block(x: jnp.ndarray, params: Dict, mc: MoEConfig,
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Apply MoE. x: (B, S, D) or (T, D). Returns (out, aux_loss).

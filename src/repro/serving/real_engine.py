@@ -78,6 +78,7 @@ from repro.models.model import (
     paged_clear_rows, paged_copy_block, paged_decode_step,
     paged_gather_blocks, paged_layout, paged_prefill_step, prefill_chunk,
 )
+from repro.serving import trace
 from repro.serving.engine import SimDecodeInstance, SimPrefillInstance
 from repro.serving.kv_pool import BlockPool, pad_block_table
 from repro.serving.page_share import PagePrefixBinder
@@ -87,6 +88,16 @@ from repro.serving.plane import ASYNC, PassResult, StartResult, UnifiedEngine
 # ---------------------------------------------------------------------------
 # Shared engine spec + KV handoff bus
 # ---------------------------------------------------------------------------
+
+
+def _with_cfg(fn, cfg):
+    """`fn` with the model config bound as its first argument, under
+    `fn`'s own name: `jax.jit` names the program after it.  (A
+    `functools.partial` would be lowered as `jit__unknown`.)"""
+    def call(*args):
+        return fn(cfg, *args)
+    call.__name__ = call.__qualname__ = fn.__name__
+    return call
 
 
 class _LockedJit:
@@ -158,37 +169,31 @@ class EngineSpec:
 
     def __post_init__(self):
         cfg = self.cfg
-        self.jit_prefill_chunk = jax.jit(
-            lambda p, t, c: prefill_chunk(cfg, p, t, c))
-        self.jit_decode = jax.jit(
-            lambda p, t, c: decode_step(cfg, p, t, c))
+        # every program is jitted under its model function's name, so a
+        # profiler trace names it (`jit_paged_decode_step`, ...)
+        self.jit_prefill_chunk = jax.jit(_with_cfg(prefill_chunk, cfg))
+        self.jit_decode = jax.jit(_with_cfg(decode_step, cfg))
         self.jit_join = jax.jit(cache_join)
         if self.block_size:
             self.nbt, _ = paged_layout(cfg, self.max_len, self.block_size)
             self.jit_paged_decode = jax.jit(
-                lambda p, t, c: paged_decode_step(cfg, p, t, c))
-            self.jit_paged_join = jax.jit(
-                lambda d, s, slot, tab: paged_cache_join(cfg, d, s, slot,
-                                                         tab))
+                _with_cfg(paged_decode_step, cfg))
+            self.jit_paged_join = jax.jit(_with_cfg(paged_cache_join, cfg))
             # page-native prefill + block-granular handoff (one jitted
             # shape each: tables/masks are padded to nbt width, slot and
             # block ids are traced scalars)
             self.jit_paged_prefill = jax.jit(
-                lambda p, t, c, slot: paged_prefill_step(cfg, p, t, c, slot))
+                _with_cfg(paged_prefill_step, cfg))
             self.jit_gather_blocks = jax.jit(
-                lambda c, ids: paged_gather_blocks(cfg, c, ids))
+                _with_cfg(paged_gather_blocks, cfg))
             self.jit_adopt_blocks = jax.jit(
-                lambda d, pay, slot, tab, cm, km, cur: paged_adopt_blocks(
-                    cfg, d, pay, slot, tab, cm, km, cur))
-            self.jit_copy_block = jax.jit(
-                lambda c, src, dst: paged_copy_block(cfg, c, src, dst))
+                _with_cfg(paged_adopt_blocks, cfg))
+            self.jit_copy_block = jax.jit(_with_cfg(paged_copy_block, cfg))
             self.jit_clear_rows = jax.jit(paged_clear_rows)
             # unified mixed-batch step (decode rows + piggybacked prefill
             # chunks in one XLA program): retraces per (n_chunks, chunk
             # lengths) combination — slots and masks are traced
-            self.jit_mixed = jax.jit(
-                lambda p, t, c, chunks, mask: mixed_step(cfg, p, t, c,
-                                                         chunks, mask))
+            self.jit_mixed = jax.jit(_with_cfg(mixed_step, cfg))
         self.n_dp = 1
         self.axis_map = None
         self._mesh_lock = threading.RLock()
@@ -246,6 +251,7 @@ class EngineSpec:
                                                   self.params)))
 
         def sharded(fn):
+            @functools.wraps(fn)
             def wrapped(p, t, c, *rest):
                 with annotate.activate(mesh, self.axis_map,
                                        ep_shard_map=True):
@@ -256,13 +262,9 @@ class EngineSpec:
                     jax.lax.with_sharding_constraint(out[-1], cspec),)
             return jax.jit(wrapped)
 
-        self.jit_paged_decode = sharded(
-            lambda p, t, c: paged_decode_step(cfg, p, t, c))
-        self.jit_paged_prefill = sharded(
-            lambda p, t, c, slot: paged_prefill_step(cfg, p, t, c, slot))
-        self.jit_mixed = sharded(
-            lambda p, t, c, chunks, mask: mixed_step(cfg, p, t, c,
-                                                     chunks, mask))
+        self.jit_paged_decode = sharded(_with_cfg(paged_decode_step, cfg))
+        self.jit_paged_prefill = sharded(_with_cfg(paged_prefill_step, cfg))
+        self.jit_mixed = sharded(_with_cfg(mixed_step, cfg))
         # EVERY jit becomes a mesh program once params are sharded (the
         # dense-path prefill chunk carries an all-reduce over the
         # data-sharded expert weights, joins/gathers reshard sharded
@@ -660,9 +662,10 @@ class RealPrefillEngine(SimPrefillInstance, _WorkerOwner):
             batch = self._strip_unstaged(batch)
             if batch is None:
                 return None
-        post = self._post        # bound per run: an abandoned job cannot
-        self._worker.submit(     # post into a later run's loop
-            lambda: self._exec_pass(batch, post))
+        # the loop's post is bound per run: an abandoned job cannot post
+        # into a later run's loop
+        post, cause = self._post, trace.current()
+        self._worker.submit(lambda: self._exec_pass(batch, post, cause))
         return ASYNC
 
     def _strip_unstaged(self, batch: Dict[int, List[Tuple[Request, int]]]
@@ -700,14 +703,16 @@ class RealPrefillEngine(SimPrefillInstance, _WorkerOwner):
         return kept
 
     def _exec_pass(self, batch: Dict[int, List[Tuple[Request, int]]],
-                   post) -> None:
+                   post, cause: Optional[int] = None) -> None:
         # worker thread: pure model execution on engine-private contexts
-        try:
-            for taken in batch.values():
-                for req, tok in taken:
-                    self._run_chunk(req, tok)
-        except BaseException as e:      # surface on the runtime thread
-            self._error = e
+        with trace.span("prefill.pass", instance=self.instance_id,
+                        cause=cause):
+            try:
+                for taken in batch.values():
+                    for req, tok in taken:
+                        self._run_chunk(req, tok)
+            except BaseException as e:      # surface on the runtime thread
+                self._error = e
         post("pass_end", self)
 
     def _run_chunk(self, req: Request, tok: int) -> None:
@@ -719,13 +724,16 @@ class RealPrefillEngine(SimPrefillInstance, _WorkerOwner):
             ctx = self._ctx[req.rid] = _PrefillCtx(self.spec)
         ids = (req.tokens or ())[ctx.consumed: ctx.consumed + tok]
         if ids:
-            arr = jnp.asarray([ids], jnp.int32)
             with self.spec.device_lock():
-                logits, ctx.cache = self.spec.jit_prefill_chunk(
-                    self.spec.params, arr, ctx.cache)
+                with trace.span("prefill.chunk", rid=req.rid,
+                                tokens=len(ids)):
+                    arr = jnp.asarray([ids], jnp.int32)
+                    logits, ctx.cache = self.spec.jit_prefill_chunk(
+                        self.spec.params, arr, ctx.cache)
                 ctx.consumed += len(ids)
                 if ctx.consumed >= req.input_len and ctx.first_token is None:
-                    ctx.first_token = int(jnp.argmax(logits[0]))
+                    with trace.span("prefill.first_token", rid=req.rid):
+                        ctx.first_token = int(jnp.argmax(logits[0]))
 
     def _run_chunk_paged(self, req: Request, tok: int) -> None:
         # worker thread: extend the request's cache row in place; the
@@ -735,14 +743,16 @@ class RealPrefillEngine(SimPrefillInstance, _WorkerOwner):
         ids = (req.tokens or ())[ctx.consumed: ctx.consumed + tok]
         if not ids:
             return
-        arr = jnp.asarray([ids], jnp.int32)
         with self.spec.device_lock():
-            logits, self.cache = self.spec.jit_paged_prefill(
-                self.spec.params, arr, self.cache, ctx.slot)
+            with trace.span("prefill.chunk", rid=req.rid, tokens=len(ids)):
+                arr = jnp.asarray([ids], jnp.int32)
+                logits, self.cache = self.spec.jit_paged_prefill(
+                    self.spec.params, arr, self.cache, ctx.slot)
             self.chunks_run += 1
             ctx.consumed += len(ids)
             if ctx.consumed >= req.input_len and ctx.first_token is None:
-                ctx.first_token = int(jnp.argmax(logits[0]))
+                with trace.span("prefill.first_token", rid=req.rid):
+                    ctx.first_token = int(jnp.argmax(logits[0]))
 
     def finish_pass(self, now: float) -> PassResult:
         self._raise_worker_error()
@@ -1177,13 +1187,21 @@ class RealDecodeEngine(SimDecodeInstance, _WorkerOwner):
             st.pool.free([old])
             self.cow_copies += 1
 
+    def _joins(self, now: float, dp_states) -> None:
+        """Apply the buffered joins, as a `decode.joins` span counting the
+        requests taken off the pending list."""
+        with trace.span("decode.joins", instance=self.instance_id):
+            n = len(self._pending)
+            self._apply_joins(now, dp_states)
+            trace.count("decode.joins", n - len(self._pending))
+
     def start_step(self, dp_states, now: Optional[float] = None
                    ) -> StartResult:
         self._raise_worker_error()
         if self.busy:
             return None
         if self._pending:
-            self._apply_joins(now if now is not None else 0.0, dp_states)
+            self._joins(now if now is not None else 0.0, dp_states)
         if not super().has_work():
             return None
         self.busy = True
@@ -1211,41 +1229,68 @@ class RealDecodeEngine(SimDecodeInstance, _WorkerOwner):
         self._step_rows = (len(self._group.slots) if self.spec.sharded
                            else sum(len(self._dp[d].slots)
                                     for d in self._participants))
-        epoch = self.epoch
-        post = self._post
-        self._worker.submit(lambda: self._exec_step(jobs, epoch, post))
+        epoch, post, cause = self.epoch, self._post, trace.current()
+        self._worker.submit(lambda: self._exec_step(jobs, epoch, post, cause))
         return ASYNC
 
-    def _exec_step(self, jobs, epoch: int, post) -> None:
+    def _step_span(self, cause: Optional[int]):
+        return trace.span("decode.step", instance=self.instance_id,
+                          step=self.steps, rows=self._step_active,
+                          cause=cause)
+
+    @staticmethod
+    def _readout(logits, dp_id: int, firsts=()) -> Tuple[List[int],
+                                                          List[int]]:
+        """The host's next tokens of a DP job: each row's argmax, then
+        each chunk's first token, one device-to-host read per `int`."""
+        with trace.span("decode.readout", dp=dp_id):
+            nxt = ([] if logits is None
+                   else [int(x) for x in jnp.argmax(logits, axis=-1)])
+            first = [int(jnp.argmax(lg[0])) for lg in firsts]
+            trace.count("decode.host_reads", len(nxt) + len(first))
+        return nxt, first
+
+    def _exec_step(self, jobs, epoch: int, post,
+                   cause: Optional[int] = None) -> None:
         # worker thread: one batched decode_step per occupied DP (the
         # instance-level sync barrier = all DPs in one serial job)
-        t0 = time.monotonic()
-        step = (self.spec.jit_paged_decode if self.spec.paged
-                else self.spec.jit_decode)
-        try:
-            res: Dict[int, Tuple[Dict, List[int]]] = {}
-            for dp_id, cache, toks in jobs:
-                with self.spec.device_lock():
-                    logits, new_cache = step(self.spec.params, toks, cache)
-                    nxt = [int(x) for x in jnp.argmax(logits, axis=-1)]
-                if dp_id < 0:
-                    # merged cross-DP job: slots are global, so the same
-                    # (cache, next-token) pair fans back to every
-                    # participating DP — finish_step indexes nxt by the
-                    # participant's global slot unchanged
-                    for d in self._participants:
-                        res[d] = (new_cache, nxt)
-                else:
-                    res[dp_id] = (new_cache, nxt)
-            self._result = res
-        except BaseException as e:      # surface on the runtime thread
-            self._error = e
-        dur = time.monotonic() - t0
-        self.step_samples.append((dur, self._step_active, self._step_rows))
+        with self._step_span(cause):
+            t0 = time.monotonic()
+            step = (self.spec.jit_paged_decode if self.spec.paged
+                    else self.spec.jit_decode)
+            try:
+                res: Dict[int, Tuple[Dict, List[int]]] = {}
+                for dp_id, cache, toks in jobs:
+                    with self.spec.device_lock():
+                        with trace.span("decode.launch", dp=dp_id):
+                            logits, new_cache = step(self.spec.params, toks,
+                                                     cache)
+                        nxt, _ = self._readout(logits, dp_id)
+                    if dp_id < 0:
+                        # merged cross-DP job: slots are global, so the
+                        # same (cache, next-token) pair fans back to every
+                        # participating DP — finish_step indexes nxt by
+                        # the participant's global slot unchanged
+                        for d in self._participants:
+                            res[d] = (new_cache, nxt)
+                    else:
+                        res[dp_id] = (new_cache, nxt)
+                self._result = res
+            except BaseException as e:      # surface on the runtime thread
+                self._error = e
+            dur = time.monotonic() - t0
+            self.step_samples.append((dur, self._step_active,
+                                      self._step_rows))
         post("step_end", (self, epoch, dur))
 
     def finish_step(self, now: float, dp_states) -> List[Request]:
         self._raise_worker_error()
+        with trace.span("decode.finish", instance=self.instance_id,
+                        step=self.steps):
+            return self._finish_step(now, dp_states)
+
+    def _finish_step(self, now: float, dp_states) -> List[Request]:
+        """Append each row's token, leave finished requests' slots."""
         res, self._result = self._result, None
         parts, self._participants = self._participants, {}
         assert res is not None
@@ -1456,7 +1501,7 @@ class RealUnifiedEngine(RealDecodeEngine, UnifiedEngine):
         if self.busy:
             return None
         if self._pending:
-            self._apply_joins(now if now is not None else 0.0, dp_states)
+            self._joins(now if now is not None else 0.0, dp_states)
         if not (SimDecodeInstance.has_work(self)
                 or any(self.prefilling[d] for d in self.dp_ids)):
             return None
@@ -1508,9 +1553,9 @@ class RealUnifiedEngine(RealDecodeEngine, UnifiedEngine):
             len(self._group.slots) if self.spec.sharded
             else sum(len(self._dp[d].slots)
                      for d, _c, toks, _ch, _m in jobs if toks is not None))
-        epoch = self.epoch
-        post = self._post
-        self._worker.submit(lambda: self._exec_mixed(jobs, epoch, post))
+        epoch, post, cause = self.epoch, self._post, trace.current()
+        self._worker.submit(
+            lambda: self._exec_mixed(jobs, epoch, post, cause))
         return ASYNC
 
     def _merge_sharded_jobs(self, jobs):
@@ -1533,60 +1578,64 @@ class RealUnifiedEngine(RealDecodeEngine, UnifiedEngine):
                 m[s] = True
         return [(-1, g.cache, toks, chunks, jnp.asarray(m))]
 
-    def _exec_mixed(self, jobs, epoch: int, post) -> None:
+    def _exec_mixed(self, jobs, epoch: int, post,
+                    cause: Optional[int] = None) -> None:
         # worker thread: one fused mixed step per DP with decode rows
         # (masked when prefilling residents share the cache), a plain
         # paged decode when nothing is prefilling, a serial chunk loop
         # when nothing is decoding
-        t0 = time.monotonic()
-        try:
-            res: Dict[int, Tuple[Dict, List[int]]] = {}
-            cres: Dict[int, List[int]] = {}
-            for dp_id, cache, toks, chunks, mask in jobs:
-                with self.spec.device_lock():
-                    if toks is None:
-                        new_cache = cache
-                        clogits = []
-                        for ctoks, slot in chunks:
-                            lg, new_cache = self.spec.jit_paged_prefill(
-                                self.spec.params, ctoks, new_cache, slot)
-                            clogits.append(lg)
-                        nxt: List[int] = []
-                    elif mask is not None:
-                        logits, clogits, new_cache = self.spec.jit_mixed(
-                            self.spec.params, toks, cache, chunks, mask)
-                        if chunks:
-                            self.mixed_steps += 1
-                        nxt = [int(x) for x in jnp.argmax(logits, axis=-1)]
-                    else:
-                        logits, new_cache = self.spec.jit_paged_decode(
-                            self.spec.params, toks, cache)
-                        clogits = ()
-                        nxt = [int(x) for x in jnp.argmax(logits, axis=-1)]
-                    firsts = [int(jnp.argmax(lg[0])) for lg in clogits]
-                if dp_id < 0:
-                    # merged cross-DP job: one cache/next-token pair fans
-                    # back to every decoding DP (slots are global); the
-                    # flat chunk firsts split by per-DP grant counts in
-                    # the same order _merge_sharded_jobs flattened them
-                    i = 0
-                    for d, lst in self._grants.items():
-                        cres[d] = firsts[i:i + len(lst)]
-                        i += len(lst)
-                    if self._participants:
-                        for d in self._participants:
-                            res[d] = (new_cache, nxt)
-                    else:
-                        res[self.dp_ids[0]] = (new_cache, [])
-                    continue
-                res[dp_id] = (new_cache, nxt)
-                cres[dp_id] = firsts
-            self._result = res
-            self._chunk_result = cres
-        except BaseException as e:      # surface on the runtime thread
-            self._error = e
-        dur = time.monotonic() - t0
-        self.step_samples.append((dur, self._step_active, self._step_rows))
+        spec = self.spec
+        with self._step_span(cause):
+            t0 = time.monotonic()
+            try:
+                res: Dict[int, Tuple[Dict, List[int]]] = {}
+                cres: Dict[int, List[int]] = {}
+                for dp_id, cache, toks, chunks, mask in jobs:
+                    with spec.device_lock():
+                        with trace.span("decode.launch", dp=dp_id):
+                            logits = None
+                            if toks is None:
+                                new_cache = cache
+                                clogits = []
+                                for ctoks, slot in chunks:
+                                    lg, new_cache = spec.jit_paged_prefill(
+                                        spec.params, ctoks, new_cache, slot)
+                                    clogits.append(lg)
+                            elif mask is not None:
+                                logits, clogits, new_cache = spec.jit_mixed(
+                                    spec.params, toks, cache, chunks, mask)
+                                if chunks:
+                                    self.mixed_steps += 1
+                            else:
+                                logits, new_cache = spec.jit_paged_decode(
+                                    spec.params, toks, cache)
+                                clogits = ()
+                        nxt, firsts = self._readout(logits, dp_id, clogits)
+                    if dp_id < 0:
+                        # merged cross-DP job: one cache/next-token pair
+                        # fans back to every decoding DP (slots are
+                        # global); the flat chunk firsts split by per-DP
+                        # grant counts in the same order
+                        # _merge_sharded_jobs flattened them
+                        i = 0
+                        for d, lst in self._grants.items():
+                            cres[d] = firsts[i:i + len(lst)]
+                            i += len(lst)
+                        if self._participants:
+                            for d in self._participants:
+                                res[d] = (new_cache, nxt)
+                        else:
+                            res[self.dp_ids[0]] = (new_cache, [])
+                        continue
+                    res[dp_id] = (new_cache, nxt)
+                    cres[dp_id] = firsts
+                self._result = res
+                self._chunk_result = cres
+            except BaseException as e:      # surface on the runtime thread
+                self._error = e
+            dur = time.monotonic() - t0
+            self.step_samples.append((dur, self._step_active,
+                                      self._step_rows))
         post("step_end", (self, epoch, dur))
 
     def finish_step(self, now: float, dp_states) -> List[Request]:

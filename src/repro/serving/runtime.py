@@ -39,6 +39,10 @@ completion within its watchdog budget), the instance is drained, its KV
 accounting is released, and the stranded requests are re-placed on the
 healthy instances through the scheduler's load-aware allocator.
 
+With `repro.serving.trace` on, each event handled, with the drive of the
+planes after it, is a `runtime.<kind>` span, and the prefill scheduler's
+poll and dispatch a `sched.poll` span inside it.
+
 SLO-aware overload control (both opt-in, see ServingConfig):
 
   admission   a `FlowController` gates arrivals: while the decode pool is
@@ -68,7 +72,24 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 from repro.core.decode_alloc import kv_footprint, select_victims
 from repro.core.flow_control import FlowAction, FlowController
 from repro.core.types import Request, RequestPhase
+from repro.serving import trace
 from repro.serving.plane import ASYNC, DecodeEngine, PrefillEngine
+
+# the runtime thread's span around each event and the drive after it
+_EVENT_SPAN = {k: f"runtime.{k}" for k in (
+    "arrival", "pass_end", "kv_arrived", "step_end", "tick")}
+
+
+def _event_span(kind: str, payload):
+    if not trace.enabled():
+        return trace.OFF
+    if kind in ("arrival", "kv_arrived"):
+        return trace.span(_EVENT_SPAN[kind], rid=payload.rid)
+    if kind == "pass_end":
+        return trace.span(_EVENT_SPAN[kind], instance=payload.instance_id)
+    if kind == "step_end":
+        return trace.span(_EVENT_SPAN[kind], instance=payload[0].instance_id)
+    return trace.span(_EVENT_SPAN[kind])
 
 
 class EventLoop:
@@ -417,96 +438,106 @@ class ClusterRuntime:
             now, _, kind, payload = item
             if now > horizon:
                 break
-            if kind == "arrival":
-                req: Request = payload
-                act = FlowAction.ADMIT
-                if self.flow is not None and self.decode:
-                    act = self.flow.gate(req, self._decode_saturated())
-                if act == FlowAction.THROTTLE:
-                    ev.push(now + self.flow.backoff(req.wait_cycles),
-                            "arrival", req)
-                elif act == FlowAction.REJECT:
-                    req.phase = RequestPhase.REJECTED
-                    self.rejected.append(req)
-                elif self.psched is not None:
-                    self.psched.on_arrival(req, now)
-                else:
-                    self._handoff(req, now)
-            elif kind == "pass_end":
-                inst: PrefillEngine = payload
-                if self.realtime:
-                    self._inflight -= 1
-                start = self._pass_start.pop(inst.instance_id)
-                res = inst.finish_pass(now)
-                for e in res.end_forwards:
-                    e.exec_time = now - start
-                    self.psched.on_end_forward(e)
-                if self.dsched is not None:
-                    for req in res.completed:
-                        delay = (self.transfer_time(req)
-                                 if self.transfer_time else 0.0)
-                        ev.push(now + delay, "kv_arrived", req)
-            elif kind == "kv_arrived":
-                self._handoff(payload, now)
-            elif kind == "step_end":
-                dinst, epoch, step_dur = payload
-                if self.realtime:
-                    self._inflight -= 1
-                if epoch != dinst.epoch:
-                    pass        # stale: the instance was drained mid-step
-                else:
-                    done = dinst.finish_step(now, self.state.decode_dps)
-                    if self.dsched is not None and hasattr(self.dsched,
-                                                           "on_step_end"):
-                        self.dsched.on_step_end(dinst.instance_id, now,
-                                                step_time=step_dur)
-                    if closed_loop:
-                        for _ in done:
-                            nxt = next(pool, None)
-                            if nxt is not None:
-                                nxt.arrival_time = now
-                                ev.push(now, "arrival", nxt)
-                    self._steps += 1
-                    self._snapshot()
-            elif kind == "tick":
-                if (self._next_tick is not None
-                        and now >= self._next_tick - 1e-9):
-                    self._next_tick = None
-            # drive every plane after any event ----------------------------
-            if self.psched is not None:
-                for cmd in self.psched.poll(now):
-                    self.prefill[cmd.instance_id].enqueue(cmd, now)
-                for inst in self.prefill:
-                    dur = inst.start_pass(now)
-                    if dur is ASYNC:
-                        self._pass_start[inst.instance_id] = now
-                        self._inflight += 1
-                    elif dur is not None:
-                        self._pass_start[inst.instance_id] = now
-                        ev.push(now + dur, "pass_end", inst)
-            if self.dsched is not None:
-                self._place(self.dsched.poll(now), now)
-                self._place(self._redispatch_stalled(now), now)
-                if self.preemption:
-                    self._place(self._readmit_parked(now), now)
-                    self._preempt_pressure(now)
-                for dinst in self.decode:
-                    dur = dinst.start_step(self.state.decode_dps, now)
-                    if dur is ASYNC:
-                        self._inflight += 1
-                    elif dur is not None:
-                        ev.push(now + dur, "step_end",
-                                (dinst, dinst.epoch, dur))
-            # wake-ups -----------------------------------------------------
-            for sched in (self.psched, self.dsched):
-                if sched is not None:
-                    self._schedule_tick(ev, sched.next_event_time(now), now)
+            with _event_span(kind, payload):
+                self._handle(ev, now, kind, payload, closed_loop, pool)
+                self._drive(ev, now)
             # realtime early exit: every request settled — don't sleep out
             # residual ticks
             if (self.realtime and not closed_loop and template
                     and self._inflight == 0 and self._all_settled(template)):
                 break
         return now
+
+    def _handle(self, ev: EventLoop, now: float, kind: str, payload,
+                closed_loop: int, pool: Iterator[Request]) -> None:
+        """Apply one event to the planes (runtime thread)."""
+        if kind == "arrival":
+            req: Request = payload
+            act = FlowAction.ADMIT
+            if self.flow is not None and self.decode:
+                act = self.flow.gate(req, self._decode_saturated())
+            if act == FlowAction.THROTTLE:
+                ev.push(now + self.flow.backoff(req.wait_cycles),
+                        "arrival", req)
+            elif act == FlowAction.REJECT:
+                req.phase = RequestPhase.REJECTED
+                self.rejected.append(req)
+            elif self.psched is not None:
+                self.psched.on_arrival(req, now)
+            else:
+                self._handoff(req, now)
+        elif kind == "pass_end":
+            inst: PrefillEngine = payload
+            if self.realtime:
+                self._inflight -= 1
+            start = self._pass_start.pop(inst.instance_id)
+            res = inst.finish_pass(now)
+            for e in res.end_forwards:
+                e.exec_time = now - start
+                self.psched.on_end_forward(e)
+            if self.dsched is not None:
+                for req in res.completed:
+                    delay = (self.transfer_time(req)
+                             if self.transfer_time else 0.0)
+                    ev.push(now + delay, "kv_arrived", req)
+        elif kind == "kv_arrived":
+            self._handoff(payload, now)
+        elif kind == "step_end":
+            dinst, epoch, step_dur = payload
+            if self.realtime:
+                self._inflight -= 1
+            if epoch != dinst.epoch:
+                pass        # stale: the instance was drained mid-step
+            else:
+                done = dinst.finish_step(now, self.state.decode_dps)
+                if self.dsched is not None and hasattr(self.dsched,
+                                                       "on_step_end"):
+                    self.dsched.on_step_end(dinst.instance_id, now,
+                                            step_time=step_dur)
+                if closed_loop:
+                    for _ in done:
+                        nxt = next(pool, None)
+                        if nxt is not None:
+                            nxt.arrival_time = now
+                            ev.push(now, "arrival", nxt)
+                self._steps += 1
+                self._snapshot()
+        elif kind == "tick":
+            if (self._next_tick is not None
+                    and now >= self._next_tick - 1e-9):
+                self._next_tick = None
+
+    def _drive(self, ev: EventLoop, now: float) -> None:
+        """Drive every plane after any event, then schedule the next
+        wake-up."""
+        if self.psched is not None:
+            with trace.span("sched.poll"):
+                for cmd in self.psched.poll(now):
+                    self.prefill[cmd.instance_id].enqueue(cmd, now)
+            for inst in self.prefill:
+                dur = inst.start_pass(now)
+                if dur is ASYNC:
+                    self._pass_start[inst.instance_id] = now
+                    self._inflight += 1
+                elif dur is not None:
+                    self._pass_start[inst.instance_id] = now
+                    ev.push(now + dur, "pass_end", inst)
+        if self.dsched is not None:
+            self._place(self.dsched.poll(now), now)
+            self._place(self._redispatch_stalled(now), now)
+            if self.preemption:
+                self._place(self._readmit_parked(now), now)
+                self._preempt_pressure(now)
+            for dinst in self.decode:
+                dur = dinst.start_step(self.state.decode_dps, now)
+                if dur is ASYNC:
+                    self._inflight += 1
+                elif dur is not None:
+                    ev.push(now + dur, "step_end",
+                            (dinst, dinst.epoch, dur))
+        for sched in (self.psched, self.dsched):
+            if sched is not None:
+                self._schedule_tick(ev, sched.next_event_time(now), now)
 
     # -- aggregate stats ---------------------------------------------------
 
