@@ -1242,12 +1242,17 @@ class RealDecodeEngine(SimDecodeInstance, _WorkerOwner):
     def _readout(logits, dp_id: int, firsts=()) -> Tuple[List[int],
                                                           List[int]]:
         """The host's next tokens of a DP job: each row's argmax, then
-        each chunk's first token, one device-to-host read per `int`."""
+        each chunk's first token, taken on the device and brought to the
+        host in one `device_get`: one blocking round trip whatever the
+        rows, where iterating the device array would run a program and
+        block once per row, with the chip idle."""
         with trace.span("decode.readout", dp=dp_id):
-            nxt = ([] if logits is None
-                   else [int(x) for x in jnp.argmax(logits, axis=-1)])
-            first = [int(jnp.argmax(lg[0])) for lg in firsts]
+            rows = [] if logits is None else [jnp.argmax(logits, axis=-1)]
+            host = jax.device_get(rows + [jnp.argmax(lg[0]) for lg in firsts])
+            nxt = host[0].tolist() if rows else []
+            first = [int(x) for x in host[len(rows):]]
             trace.count("decode.host_reads", len(nxt) + len(first))
+            trace.count("decode.host_transfers")
         return nxt, first
 
     def _exec_step(self, jobs, epoch: int, post,

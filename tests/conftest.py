@@ -11,6 +11,17 @@ sys.path.insert(0, os.path.join(_HERE, "..", "src"))
 sys.path.insert(0, _HERE)           # sibling imports (_hypothesis_shim)
 
 
+@pytest.fixture
+def recorder():
+    """The recorder on and empty for one test, off and empty after it."""
+    from repro.serving import trace
+    trace.reset()
+    trace.enable()
+    yield trace
+    trace.disable()
+    trace.reset()
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: heavyweight model/jit cases (deselect with "

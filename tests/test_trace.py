@@ -8,7 +8,8 @@ path records with it:
   * every `EngineSpec` program is jitted under its model function's name
   * a paged P/D serve records the layer boundaries: one `decode.step` per
     `step_samples` entry, of the same length within 1 ms, each with its
-    DP jobs' launch and readout and the readout's host reads
+    DP jobs' launch and readout, and the readout's rows brought to the
+    host in one transfer
 """
 import random
 import re
@@ -27,16 +28,6 @@ from repro.serving.server import RealSBSServer
 
 MAX_LEN = 96
 BLOCK = 16
-
-
-@pytest.fixture
-def recorder():
-    """The recorder on and empty for one test, off and empty after it."""
-    trace.reset()
-    trace.enable()
-    yield trace
-    trace.disable()
-    trace.reset()
 
 
 @pytest.fixture
@@ -284,17 +275,19 @@ def test_serve_records_every_layer_boundary(served):
     chunks = [s for s in spans if s.name == "prefill.chunk"]
     assert sum(s.ids["tokens"] for s in chunks) == sum(
         r.input_len for r in reqs)
-    # each readout reads every row of its DP job once; a step carries
-    # its jobs' reads
+    # each readout brings every row of its DP job to the host in one
+    # transfer; a step carries its jobs' reads and transfers
     slots = srv.spec.paged_slots
     for s in spans:
         if s.name == "decode.readout":
-            assert s.counts == {"decode.host_reads": slots}
+            assert s.counts == {"decode.host_reads": slots,
+                                "decode.host_transfers": 1}
         if s.name == "decode.step":
             jobs = sum(1 for c in spans if c.parent == s.id
                        and c.name == "decode.readout")
             assert s.counts["decode.host_reads"] == jobs * slots
-    assert counts["decode.host_reads"] == sum(
-        s.counts["decode.host_reads"] for s in spans
-        if s.name == "decode.step")
+            assert s.counts["decode.host_transfers"] == jobs
+    for name in ("decode.host_reads", "decode.host_transfers"):
+        assert counts[name] == sum(s.counts[name] for s in spans
+                                   if s.name == "decode.step")
     assert counts["decode.joins"] == len(reqs)
